@@ -320,23 +320,35 @@ def _fan_in(shape: Tuple[int, ...]) -> int:
     return shape[-2] if len(shape) >= 2 else max(shape[-1], 1)
 
 
-def _init_one(key, spec: ParamSpec) -> jnp.ndarray:
+def _init_std(spec: ParamSpec) -> Optional[float]:
+    """The initializer's stddev, or None for the constant inits."""
+    if spec.init in ("zeros", "ones"):
+        return None
+    if spec.scale is not None:
+        return spec.scale
+    if spec.init == "normal":
+        return _fan_in(spec.shape) ** -0.5
+    if spec.init == "embed":
+        # lookup table: variance set by the embedding dim, not the vocab
+        return spec.shape[-1] ** -0.5
+    if spec.init == "small":
+        # token-shift mixing coefficients and per-head bonuses start near 0
+        return 0.02
+    raise ValueError(f"unknown init {spec.init!r} for shape {spec.shape}")
+
+
+def _init_one(key, spec: ParamSpec, std, keep=None) -> jnp.ndarray:
     if spec.init == "zeros":
         return jnp.zeros(spec.shape, spec.dtype)
     if spec.init == "ones":
         return jnp.ones(spec.shape, spec.dtype)
-    if spec.init == "normal":
-        std = spec.scale if spec.scale is not None else _fan_in(spec.shape) ** -0.5
-    elif spec.init == "embed":
-        # lookup table: variance set by the embedding dim, not the vocab
-        std = spec.scale if spec.scale is not None else spec.shape[-1] ** -0.5
-    elif spec.init == "small":
-        # token-shift mixing coefficients and per-head bonuses start near 0
-        std = spec.scale if spec.scale is not None else 0.02
-    else:
-        raise ValueError(f"unknown init {spec.init!r} for shape {spec.shape}")
-    x = jax.random.normal(key, spec.shape, jnp.float32) * std
-    return x.astype(spec.dtype)
+    x = jax.random.normal(key, spec.shape, jnp.float32)
+    if keep is not None:
+        # under jit: a select on a runtime flag (always true) that XLA
+        # cannot see through, so it does not fold ``std`` into the
+        # normal's own sqrt(2) factor and round differently than eager
+        x = jnp.where(keep, x, 0.0)
+    return (x * std).astype(spec.dtype)
 
 
 def init_params(key, specs):
@@ -351,7 +363,30 @@ def init_params(key, specs):
         return specs
     keys = jax.random.split(key, len(leaves))
     return treedef.unflatten(
-        [_init_one(k, s) for k, s in zip(keys, leaves)])
+        [_init_one(k, s, _init_std(s)) for k, s in zip(keys, leaves)])
+
+
+def init_params_sharded(key, specs, shardings):
+    """:func:`init_params` as ONE jitted program writing ``shardings``.
+
+    The float32 draws fuse into their casts instead of occupying device
+    memory, and on a sharded mesh every device computes only its own
+    shard (partitionable threefry makes the draws independent of the
+    layout). The values are bit-identical to the eager
+    :func:`init_params` under the same key.
+    """
+    leaves, treedef = jax.tree.flatten(specs, is_leaf=_IS_SPEC)
+    if not leaves:
+        return specs
+    stds = [_init_std(s) for s in leaves]
+
+    def build(key, keep):
+        keys = jax.random.split(key, len(leaves))
+        return treedef.unflatten(
+            [_init_one(k, s, d, keep)
+             for k, s, d in zip(keys, leaves, stds)])
+
+    return jax.jit(build, out_shardings=shardings)(key, True)
 
 
 def abstract_params(specs):

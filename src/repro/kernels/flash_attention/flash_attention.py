@@ -22,10 +22,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# jax renamed TPUCompilerParams -> CompilerParams after 0.4.x; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                   *, nk: int, bq: int, bk: int, scale: float,
@@ -105,7 +101,7 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

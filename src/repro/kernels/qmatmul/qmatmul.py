@@ -37,10 +37,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.quant.srs import INT_RANGE
 
-# jax renamed TPUCompilerParams -> CompilerParams after 0.4.x; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 _NEUTRAL = 0
 
 
@@ -61,6 +57,42 @@ def _srs_block(acc, shift: int, out_dtype: str, rounding: str):
             raise ValueError(f"unknown rounding {rounding}")
     lo, hi = INT_RANGE[out_dtype]
     return jnp.clip(acc, lo, hi).astype(out_dtype)
+
+
+def _split_int16(a):
+    """int16 ``a`` -> int8 ``(hi, lo)`` with ``a == 256*hi + lo + 128``.
+
+    ``hi`` is the arithmetic high byte; ``lo`` is the low byte re-centred
+    into the signed int8 range, so both halves feed an int8 MXU dot.
+    """
+    a32 = a.astype(jnp.int32)
+    hi = (a32 >> 8).astype(jnp.int8)
+    lo = ((a32 & 0xFF) - 128).astype(jnp.int8)
+    return hi, lo
+
+
+def _int_dot(a, b):
+    """Exact int32 ``a @ b`` for int8/int16 operands using int8 dots only.
+
+    Mosaic refuses a matmul whose integer operands differ in width (and
+    has no int16 MXU path), so each int16 operand is split into two int8
+    bytes. The byte dots accumulate in int32 and recombine with shifts and
+    the ``+128`` re-centring term, all in wrapping int32 arithmetic — the
+    same modular result as the reference's int32 ``dot``.
+    """
+    if a.dtype == jnp.int16:
+        hi, lo = _split_int16(a)
+        colsum = jnp.sum(b.astype(jnp.int32), axis=0, keepdims=True)
+        return ((_int_dot(hi, b) << 8) + _int_dot(lo, b)
+                + (colsum << 7))
+    if b.dtype == jnp.int16:
+        hi, lo = _split_int16(b)
+        rowsum = jnp.sum(a.astype(jnp.int32), axis=1, keepdims=True)
+        return ((_int_dot(a, hi) << 8) + _int_dot(a, lo)
+                + (rowsum << 7))
+    return jax.lax.dot_general(
+        a, b, dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
 
 def _qmatmul_kernel(
@@ -86,13 +118,8 @@ def _qmatmul_kernel(
         a_i = x_ref[i * bm:(i + 1) * bm, :]
         for j in range(qn):
             w_j = w_ref[:, j * bn:(j + 1) * bn]
-            acc_ref[i * bm:(i + 1) * bm, j * bn:(j + 1) * bn] += (
-                jax.lax.dot_general(
-                    a_i, w_j,
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32,
-                )
-            )
+            acc_ref[i * bm:(i + 1) * bm, j * bn:(j + 1) * bn] += _int_dot(
+                a_i, w_j)
 
     # ---- epilogue: SRS -> ReLU -> VST (Algorithm 1 lines 12-16) ----
     @pl.when(k == nk - 1)
@@ -157,7 +184,7 @@ def qmatmul_pallas(
         out_specs=pl.BlockSpec((MB_M, MB_N), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((MB_M, MB_N), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -78,8 +78,6 @@ def run_cell(
             - ma.alias_size_in_bytes,
         }
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # older jax: one dict per device
-            ca = ca[0] if ca else {}
         text = compiled.as_text()
         if hlo_dir:
             os.makedirs(hlo_dir, exist_ok=True)

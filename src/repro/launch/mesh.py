@@ -17,7 +17,9 @@ tables use composite ``("pod", "data")`` entries that degrade gracefully
 on the 2-axis mesh, so the same config scales to N pods by growing the
 pod axis. ``make_debug_mesh(data, model)`` builds the small test/example
 mesh over however many host devices exist (the 8-device integration tests
-use a 2x4).
+use a 2x4). ``make_local_mesh()`` puts every device this process sees on
+the model axis — the serve CLI's default: 1x1 on one chip, 1x4 on a
+four-chip host.
 """
 
 from __future__ import annotations
@@ -54,3 +56,8 @@ def make_debug_mesh(data: int = 1, model: int = 1) -> Mesh:
         raise RuntimeError(f"need {n} devices, have {len(devices)}")
     return Mesh(np.asarray(devices[:n]).reshape(data, model),
                 ("data", "model"))
+
+
+def make_local_mesh() -> Mesh:
+    """1 x N ("data", "model") mesh over every device this process sees."""
+    return make_debug_mesh(1, len(jax.devices()))

@@ -9,20 +9,25 @@ plan, submits synthetic requests, and prints the counters. It dispatches
 observable after the first wave (the CI smoke job asserts hits > 0 on
 the second).
 
-Default (production) path: 16x16 single-pod mesh (2x16x16 with
---multi-pod), bucket shapes from the --shape ShapeSpec (default
-decode_32k: batch 128, context 32768). With --debug: a reduced config on
-a 1x1 host mesh with 2-sequence buckets.
+Default path: the full published config on a mesh of every device this
+process sees, all on the model axis (1x1 on one chip, 1x4 on a four-chip
+host), with bucket shapes from the --shape ShapeSpec (default serve_v5e:
+batch 8, context 2048 — sized so yi-6b serves at full depth from one
+16 GB TPU v5e). The 16x16 / 2x16x16 production meshes belong to the
+dry-run (``repro.launch.dryrun``). With --debug: a reduced config on a
+1x1 mesh with 2-sequence buckets. Each wave prints its wall-clock
+tokens/s next to the device it ran on.
 
     PYTHONPATH=src python -m repro.launch.serve --arch yi-6b --debug --tokens 4
+    python -m repro.launch.serve --arch yi-6b --schedule continuous \
+        --steps-per-dispatch 4 --paged        # on the chip, full width
 
 Flags:
   --arch       architecture alias (required), e.g. yi-6b
-  --shape      production ShapeSpec name (default decode_32k); ignored
-               under --debug
+  --shape      ShapeSpec name for the bucket shapes (default serve_v5e);
+               ignored under --debug
   --mode       sharding mode override: cascade | megatron | megatron_sp
                (default: the config's sharding_mode)
-  --multi-pod  use the 2x16x16 ("pod","data","model") mesh
   --debug      reduced config on a tiny local mesh
   --tokens     tokens to decode per request (default 8, must be >= 1)
   --quantized  int8 qmatmul decode LM head + a16w8 MLP down-projection
@@ -76,10 +81,16 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import dataclasses
+import time
+from typing import Any, Dict, List
+
+import jax
 
 from repro.models import SHAPES
 from repro.plan import MeshSpec, build_plan
 from repro.serve import BucketPolicy, DecodeRequest, ServeBatcher, make_policy
+from repro.serve.batcher import RequestResult
 
 
 def build_batcher(args) -> ServeBatcher:
@@ -88,7 +99,7 @@ def build_batcher(args) -> ServeBatcher:
         mesh_spec = MeshSpec.debug(1, 1)
         policy = BucketPolicy.debug()
     else:
-        mesh_spec = MeshSpec.production(multi_pod=args.multi_pod)
+        mesh_spec = MeshSpec.local()
         shape = SHAPES[args.shape]
         policy = BucketPolicy.production(shape.global_batch, shape.seq_len)
     plan = build_plan(args.arch, None, mode=args.mode, mesh_spec=mesh_spec,
@@ -104,7 +115,33 @@ def build_batcher(args) -> ServeBatcher:
     return batcher
 
 
-def main():
+def device_label(mesh) -> str:
+    """``platform device_kind xN`` of the devices a mesh spans."""
+    d = mesh.devices.flat[0]
+    return f"{d.platform} {d.device_kind} x{mesh.devices.size}"
+
+
+@dataclasses.dataclass
+class Wave:
+    """One request wave: its results, wall time, and the executable-cache
+    counters right after it (the next wave's lowerings/compiles must not
+    grow once the buckets are warm)."""
+
+    results: Dict[str, RequestResult]
+    seconds: float
+    cache: Dict[str, Any]
+
+
+@dataclasses.dataclass
+class ServeRun:
+    """What :func:`main` served: the batcher (plan, params, counters) and
+    its waves, for in-process callers such as ``chip_smoke.py``."""
+
+    batcher: ServeBatcher
+    waves: List[Wave]
+
+
+def main(argv=None) -> ServeRun:
     ap = argparse.ArgumentParser(
         description="Bucketed batch decode over AOT-cached executables "
                     "and resident KV/SSM state pools, wired by one "
@@ -128,13 +165,12 @@ examples:
       --policy edf --stream""")
     ap.add_argument("--arch", required=True,
                     help="architecture alias, e.g. yi-6b")
-    ap.add_argument("--shape", default="decode_32k", choices=list(SHAPES),
-                    help="production ShapeSpec (ignored under --debug)")
+    ap.add_argument("--shape", default="serve_v5e", choices=list(SHAPES),
+                    help="ShapeSpec sizing the buckets (ignored under "
+                         "--debug)")
     ap.add_argument("--mode", default=None,
                     choices=["cascade", "megatron", "megatron_sp"],
                     help="sharding mode override (default: per-arch config)")
-    ap.add_argument("--multi-pod", action="store_true",
-                    help="2x16x16 (pod,data,model) mesh instead of 16x16")
     ap.add_argument("--debug", action="store_true",
                     help="reduced config on a tiny local mesh (batch=2)")
     ap.add_argument("--tokens", type=int, default=8,
@@ -172,7 +208,7 @@ examples:
     ap.add_argument("--draft", default=None, metavar="PREFIX:N",
                     help="draft model for --speculative: 'prefix:N' = "
                          "first N target layers (default: half the stack)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.tokens < 1:
         ap.error("--tokens must be >= 1")
     if args.rounds < 1:
@@ -199,6 +235,13 @@ examples:
         ap.error("--draft needs --speculative")
 
     batcher = build_batcher(args)
+    cfg, mesh = batcher.cfg, batcher.mesh
+    device = device_label(mesh)
+    param_bytes = sum(x.nbytes for x in jax.tree.leaves(batcher.params))
+    print(f"{cfg.name}: {cfg.n_layers} layers, d_model {cfg.d_model}, "
+          f"heads {cfg.n_heads}/{cfg.n_kv} kv x {cfg.head_dim}, d_ff "
+          f"{cfg.d_ff}, vocab {cfg.vocab}; {param_bytes / 1e9:.2f} GB of "
+          f"params on {device} (mesh {dict(mesh.shape)}, {batcher.plan.mode})")
     batch = batcher.policy.buckets[0].batch
     # continuous batching is about refilling freed slots from a deep
     # queue: submit two requests per slot so slot reuse is observable
@@ -207,9 +250,7 @@ examples:
     def wave_requests(wave: int):
         # priorities/tenants cycle so --policy priority has classes to
         # order; deadlines are generous (nothing sheds in a smoke run)
-        import time as _time
-
-        deadline = (_time.monotonic() + 120.0
+        deadline = (time.monotonic() + 120.0
                     if args.policy == "edf" and args.stream else
                     1_000_000.0 if args.policy == "edf" else None)
         # under --paged every request opens with the same one-page
@@ -223,6 +264,18 @@ examples:
             tenant=f"tenant{i % 2}", deadline=deadline)
             for i in range(wave_size)]
 
+    waves: List[Wave] = []
+
+    def finish_wave(wave: int, results: Dict[str, RequestResult],
+                    seconds: float, how: str = "") -> None:
+        waves.append(Wave(results, seconds, dict(batcher.cache.stats())))
+        sample = results[sorted(results)[0]]
+        n_tok = sum(len(r.tokens) for r in results.values())
+        print(f"wave {wave}: {len(results)} requests x {args.tokens} "
+              f"tokens{how}, sample {sample.request_id} -> "
+              f"{sample.tokens[:8]}; {n_tok} tokens in {seconds:.3f}s = "
+              f"{n_tok / seconds:.1f} tok/s wall-clock on {device}")
+
     t_first = None
     if args.stream:
         from repro.serve import AsyncServeServer
@@ -230,12 +283,11 @@ examples:
         async def run_streaming():
             async with AsyncServeServer(batcher) as server:
                 for wave in range(args.rounds):
+                    t0 = time.perf_counter()
                     results = await asyncio.gather(*[
                         server.generate(r) for r in wave_requests(wave)])
-                    sample = min(results, key=lambda r: r.request_id)
-                    print(f"wave {wave}: {len(results)} requests x "
-                          f"{args.tokens} tokens (streamed), sample "
-                          f"{sample.request_id} -> {sample.tokens[:8]}")
+                    finish_wave(wave, {r.request_id: r for r in results},
+                                time.perf_counter() - t0, " (streamed)")
                 return server.stats()
 
         with batcher.plan.activate():
@@ -246,22 +298,20 @@ examples:
     else:
         with batcher.plan.activate():
             for wave in range(args.rounds):
+                t0 = time.perf_counter()
                 for r in wave_requests(wave):
                     batcher.submit(r)
                 results = batcher.run()
                 if t_first is None and results:
                     t_first = min(r.prefill_seconds
                                   for r in results.values())
-                sample = results[sorted(results)[0]]
-                print(f"wave {wave}: {len(results)} requests x "
-                      f"{args.tokens} tokens, sample {sample.request_id} "
-                      f"-> {sample.tokens[:8]}")
+                finish_wave(wave, results, time.perf_counter() - t0)
 
     stats = batcher.stats()
     for label, m in stats["buckets"].items():
         print(f"bucket {label}: {m['requests']} reqs, "
               f"{m['new_tokens']} tokens, "
-              f"{m['tokens_per_second']:.1f} tok/s host-sim, "
+              f"{m['tokens_per_second']:.1f} tok/s wall-clock on {device}, "
               f"p50 {m['p50_latency_s']:.3f}s p99 {m['p99_latency_s']:.3f}s")
     if "scheduler" in stats:
         s = stats["scheduler"]
@@ -292,7 +342,11 @@ examples:
     print(f"{batcher.cfg.name}: first token {first}; cache entries="
           f"{c['entries']} hits={c['hits']} misses={c['misses']} "
           f"lowerings={c['lowerings']} compiles={c['compiles']}")
+    return ServeRun(batcher, waves)
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -94,6 +94,10 @@ SHAPES: Dict[str, ShapeSpec] = {
     "prefill_32k": ShapeSpec("prefill_32k", 32768, 32, "prefill"),
     "decode_32k": ShapeSpec("decode_32k", 32768, 128, "decode"),
     "long_500k": ShapeSpec("long_500k", 524288, 1, "decode"),
+    # one 16 GB TPU v5e serving yi-6b at full depth: ~12.1 GB of bf16
+    # weights leave room for 8 slots x 2048 positions of paged KV
+    # (64 KiB/token, ~1.2 GB with the short bucket) plus step temporaries
+    "serve_v5e": ShapeSpec("serve_v5e", 2048, 8, "decode"),
 }
 
 # long_500k needs sub-quadratic attention: only SSM/hybrid/linear-attention

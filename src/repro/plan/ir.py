@@ -31,7 +31,7 @@ class MeshSpec:
     an already-built Mesh (tests, embedding the plan in an outer harness).
     """
 
-    kind: str = "debug"                  # "debug" | "production" | "explicit"
+    kind: str = "debug"      # "debug" | "local" | "production" | "explicit"
     data: int = 1                        # debug: data-axis extent
     model: int = 1                       # debug: model-axis extent
     multi_pod: bool = False              # production: 2x16x16 vs 16x16
@@ -40,6 +40,12 @@ class MeshSpec:
     @classmethod
     def debug(cls, data: int = 1, model: int = 1) -> "MeshSpec":
         return cls(kind="debug", data=data, model=model)
+
+    @classmethod
+    def local(cls) -> "MeshSpec":
+        """Every device present, on the model axis (1x1 on one chip, 1x4
+        on a four-chip host) — resolved when the plan is built."""
+        return cls(kind="local")
 
     @classmethod
     def production(cls, multi_pod: bool = False) -> "MeshSpec":
@@ -55,10 +61,16 @@ class MeshSpec:
             if self.mesh is None:
                 raise ValueError("explicit MeshSpec needs a mesh")
             return self.mesh
-        from repro.launch.mesh import make_debug_mesh, make_production_mesh
+        from repro.launch.mesh import (
+            make_debug_mesh,
+            make_local_mesh,
+            make_production_mesh,
+        )
 
         if self.kind == "debug":
             return make_debug_mesh(self.data, self.model)
+        if self.kind == "local":
+            return make_local_mesh()
         if self.kind == "production":
             return make_production_mesh(multi_pod=self.multi_pod)
         raise ValueError(f"unknown MeshSpec kind {self.kind!r}")
@@ -66,6 +78,8 @@ class MeshSpec:
     def label(self) -> str:
         if self.kind == "debug":
             return f"debug:{self.data}x{self.model}"
+        if self.kind == "local":
+            return "local"
         if self.kind == "production":
             return "production:2x16x16" if self.multi_pod \
                 else "production:16x16"

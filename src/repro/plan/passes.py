@@ -285,6 +285,14 @@ def quantize_pass(ir: PlanIR) -> PlanIR:
     if not ir.quantized:
         ir.record("Quantize", enabled=False)
         return ir
+    model_axis = ir.mesh.shape.get("model", 1)
+    if model_axis > 1:
+        # GSPMD cannot split a Mosaic custom call across devices: the
+        # qmatmul pallas_call would need an explicit shard_map wrapper
+        raise ValueError(
+            f"quantized serving needs a model axis of 1, got {model_axis}: "
+            "the Pallas qmatmul kernel (kernels/qmatmul) cannot be "
+            "partitioned automatically over a sharded mesh")
     cfg = ir.cfg.with_(quantized=True)
     # MLP quantization is a *serving* decision: only decode-path plans
     # (serve plans have shape=None; dry-runs may pin a decode ShapeSpec)

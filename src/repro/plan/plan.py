@@ -29,7 +29,7 @@ import jax
 
 from repro.dist.sharding import (
     abstract_params,
-    init_params,
+    init_params_sharded,
     sharding_ctx,
     specs_to_shardings,
 )
@@ -118,10 +118,17 @@ class ExecutionPlan:
         self.calibrate(params)
         return jax.device_put(params, self.param_shardings())
 
+    def _materialize(self, specs, seed: int):
+        """Random/zero leaves built straight into the plan's shardings."""
+        return init_params_sharded(
+            jax.random.PRNGKey(seed), specs,
+            specs_to_shardings(specs, self.mesh, self.rules))
+
     def init_params(self, seed: int = 0):
         """Random sharded parameters (demos, benchmarks, tests)."""
-        return self.shard_params(
-            init_params(jax.random.PRNGKey(seed), self.param_specs()))
+        params = self._materialize(self.param_specs(), seed)
+        self.calibrate(params)
+        return params
 
     def init_train_state(self, seed: int = 0):
         """(sharded params, optimizer state) ready for the train step."""
@@ -168,9 +175,7 @@ class ExecutionPlan:
             elif only == "dense":
                 sspecs = {k: s for k, s in sspecs.items()
                           if not is_paged_state_key(k)}
-        return jax.device_put(
-            init_params(jax.random.PRNGKey(0), sspecs),
-            specs_to_shardings(sspecs, self.mesh, self.rules))
+        return self._materialize(sspecs, seed=0)
 
     # -- quantization calibration ---------------------------------------------
 

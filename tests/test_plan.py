@@ -26,6 +26,7 @@ import textwrap
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import reduced_config
@@ -293,6 +294,37 @@ def test_quantized_train_plan_keeps_float_mlp(cfg):
     plan = build_plan(cfg, ShapeSpec("t", 32, 2, "train"),
                       mesh_spec=MeshSpec.debug(1, 1), quantized=True)
     assert plan.cfg.quantized and not plan.cfg.quantized_mlp
+
+
+class _FakeMesh:
+    """Just enough of a Mesh for the passes: axis names, axis sizes and a
+    device grid."""
+
+    def __init__(self, shape):
+        self.axis_names = ("data", "model")
+        self.shape = dict(zip(self.axis_names, shape))
+        self.devices = np.empty(shape, dtype=object)
+
+
+@pytest.mark.parametrize("shape", [(1, 4), (2, 2)])
+def test_quantize_pass_rejects_sharded_model_axis(cfg, shape):
+    """GSPMD cannot partition the Mosaic qmatmul call, so a quantized plan
+    on a model axis > 1 fails in the plan, naming the kernel, instead of
+    in the chip's compiler."""
+    with pytest.raises(ValueError, match="qmatmul"):
+        build_plan(cfg, None, mesh_spec=MeshSpec.from_mesh(_FakeMesh(shape)),
+                   quantized=True)
+    # a model axis of 1 is fine, and the float plan is never rejected
+    build_plan(cfg, None, mesh_spec=MeshSpec.from_mesh(_FakeMesh((4, 1))),
+               quantized=True)
+    build_plan(cfg, None, mesh_spec=MeshSpec.from_mesh(_FakeMesh(shape)))
+
+
+def test_local_mesh_spec_spans_every_device(cfg):
+    plan = build_plan(cfg, None, mesh_spec=MeshSpec.local())
+    assert plan.mesh.axis_names == ("data", "model")
+    assert plan.mesh.devices.shape == (1, len(jax.devices()))
+    assert plan.describe()["mesh"] == "local"
 
 
 # ---------------------------------------------------------------------------

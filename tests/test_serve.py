@@ -434,3 +434,51 @@ def test_serve_cli_rejects_bad_counts(monkeypatch, argv):
     with pytest.raises(SystemExit) as exc:
         serve.main()
     assert exc.value.code == 2
+
+
+def test_serve_cli_main_returns_its_waves():
+    """``main(argv)`` is callable in process (chip_smoke.py drives it):
+    it returns the batcher and one record per wave, and a warm second
+    wave of identical prompts reproduces the tokens with no new
+    lowering or compile."""
+    from repro.launch import serve
+
+    run = serve.main(["--arch", "yi-6b", "--debug", "--tokens", "3",
+                      "--schedule", "continuous", "--steps-per-dispatch",
+                      "2", "--paged"])
+    w0, w1 = run.waves
+    assert len(w0.results) == len(w1.results) == 4
+    for rid, res in w0.results.items():
+        assert w1.results["w1" + rid[2:]].tokens == res.tokens
+        assert len(res.tokens) == 3
+    assert (w1.cache["lowerings"], w1.cache["compiles"]) == \
+        (w0.cache["lowerings"], w0.cache["compiles"])
+    assert w0.seconds > 0 and run.batcher.params is not None
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_dir(tmp_path, env_dir):
+    """The entry points' cache helper defers to JAX_COMPILATION_CACHE_DIR
+    and otherwise pins <checkout>/.jax_cache. Run in a child process so
+    this test process never turns the cache on."""
+    import os
+    import subprocess
+    import sys
+
+    from repro.launch.compile_cache import DEFAULT_CACHE_DIR
+
+    env = dict(os.environ, PYTHONPATH=os.path.join(
+        os.path.dirname(__file__), "..", "src"))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / env_dir)
+    prog = ("import jax\n"
+            "from repro.launch.compile_cache import enable_compile_cache\n"
+            "print(enable_compile_cache())\n"
+            "print(jax.config.jax_compilation_cache_dir)\n")
+    out = subprocess.run([sys.executable, "-c", prog], env=env, text=True,
+                         capture_output=True, timeout=300, check=True)
+    returned, configured = out.stdout.split()
+    want = str(tmp_path / env_dir) if env_dir else str(DEFAULT_CACHE_DIR)
+    assert returned == configured == want
+    assert DEFAULT_CACHE_DIR.name == ".jax_cache"
