@@ -82,7 +82,10 @@ class DecodeRequest:
     absolute time in the scheduler's clock domain (global steps by
     default, wall-clock seconds under the async server) by which the
     last token must be produced — EDF sheds the request instead of
-    admitting it once the deadline has passed.
+    admitting it once the deadline has passed. ``t_intake`` is the
+    ``time.perf_counter()`` at which the serving stack took the request
+    in (stamped by ``AsyncServeServer`` or :meth:`ServeBatcher.submit`);
+    the scheduler reports the wait from it to admission as ``queue_us``.
     """
 
     request_id: str
@@ -91,6 +94,8 @@ class DecodeRequest:
     priority: int = 0
     tenant: str = "default"
     deadline: Optional[float] = None
+    t_intake: Optional[float] = dataclasses.field(
+        default=None, compare=False, repr=False)
 
     def __post_init__(self):
         self.prompt = [int(t) for t in self.prompt]
@@ -397,6 +402,9 @@ class ServeBatcher:
             raise ValueError(
                 f"duplicate request id {request.request_id!r}: a request "
                 "with this id is already queued")
+        if request.t_intake is None:
+            request = dataclasses.replace(request,
+                                          t_intake=time.perf_counter())
         self._pending_ids.add(request.request_id)
         self._pending.append(request)
         return request.request_id

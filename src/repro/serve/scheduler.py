@@ -63,6 +63,22 @@ Boundary seams (all host-side, none touch the compiled step):
   deterministic global step counter; the async server installs
   ``time.monotonic`` so deadlines are wall-clock.
 
+Host spans (``jax.profiler.TraceAnnotation``, about a microsecond each
+with the profiler off) put every phase of the dispatching thread on the
+profiler's timeline, beside the device's operations: ``serve.dispatch``;
+``serve.fetch``, the blocking fetch of a micro-run's tokens;
+``serve.boundary``, from that fetch's return (from the launch's where
+tokens stay on the device, from the dispatch's start for its first) to
+the return of the next launch, with children ``serve.emit``,
+``serve.intake``, ``serve.free``, ``serve.admit`` (one
+``serve.admit_request`` per admission inside, with stats ``rid``,
+``lane`` and ``queue_us``), ``serve.schedule`` and ``serve.launch``; and
+``serve.drain``. A dispatch holds one boundary per micro-run plus its
+last, which launches nothing. :meth:`ContinuousScheduler.stats` counts
+the same sections: ``boundary_seconds``, ``admit_chances``,
+``admit_stalls`` and ``admit_stalls_pages`` (docs/serving.md, "Observing
+a running server").
+
 Speculative mode (``spec=(spec_k, draft_layers)``): each micro-run
 dispatches the FUSED draft-scan + block-verify executable (see
 ``make_masked_decode_step``) instead of the plain k-step scan. At
@@ -111,6 +127,26 @@ from repro.serve.batcher import (
 from repro.serve.state_pool import StatePool
 
 _EVENT_WINDOW = 4096      # bounded: a resident server must not grow per-req
+_span = jax.profiler.TraceAnnotation
+
+
+class _Boundary:
+    """The open ``serve.boundary`` span of a dispatch, and its host time."""
+
+    def __init__(self):
+        self._span = None
+        self._t0 = 0.0
+
+    def open(self) -> None:
+        self._t0 = time.perf_counter()
+        self._span = _span("serve.boundary")
+        self._span.__enter__()
+
+    def close(self) -> float:
+        """End the span; returns its seconds."""
+        self._span.__exit__(None, None, None)
+        self._span = None
+        return time.perf_counter() - self._t0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -219,6 +255,14 @@ class ContinuousScheduler:
         self.refills = 0
         self.refill_gap_total = 0
         self.max_refill_gap = 0
+        # host time of the serve.boundary sections; boundaries where a
+        # lane was free and the queue was not empty (chances), those
+        # that admitted nothing (stalls), and the stalls where some
+        # request's positions fitted but the page budget refused it
+        self.boundary_seconds = 0.0
+        self.admit_chances = 0
+        self.admit_stalls = 0
+        self.admit_stalls_pages = 0
         # speculative decode: (lane, micro-run) verify events, draft
         # tokens proposed/accepted across them, boundary rollbacks, and
         # continuations requeued when rollbacks exhaust a bucket's
@@ -234,8 +278,6 @@ class ContinuousScheduler:
         self._spec_carry: Dict[str, List[int]] = {}
         self.events: Deque[SlotEvent] = collections.deque(
             maxlen=_EVENT_WINDOW)
-        # per-dispatch [B] idle-step vectors (benchmark slot-idle p50/p99)
-        self.dispatch_idle: Deque[List[int]] = collections.deque(maxlen=256)
         # requests to drop at the next micro-run boundary (see cancel());
         # marks never survive a boundary, so a later request reusing a
         # canceled id can never be swallowed by a stale mark
@@ -301,9 +343,12 @@ class ContinuousScheduler:
         default: first queued request that fits, skipped-prefix order
         preserved). Requests a deadline policy sheds here never run:
         they are removed from the queue, counted, and reported through
-        the shed channel.
+        the shed channel. Each admission is a ``serve.admit_request``
+        span whose ``queue_us`` is the time since the request's intake
+        stamp (``DecodeRequest.t_intake``), where it has one.
         """
         now = self._now()
+        t_boundary = time.perf_counter()
         for req in self.admission.shed(pending, now):
             self.sheds += 1
             # a shed speculative continuation delivers nothing: drop its
@@ -316,8 +361,11 @@ class ContinuousScheduler:
 
         alloc = getattr(self.pool, "allocator", None)
         lazy = self.spec is not None
+        chance = bool(pending) and any(s is None for s in slots)
+        refused_pages = False
 
         def fits(req: DecodeRequest) -> bool:
+            nonlocal refused_pages
             need = len(req.prompt) + req.max_new_tokens - 1
             if req.need_len > bucket.max_len:
                 return False
@@ -336,9 +384,13 @@ class ContinuousScheduler:
                 reserve = alloc.spec_demand(self.steps_per_dispatch) \
                     * occupied
             shared = alloc.probe(req.prompt)
-            return pos + (need - shared) <= bucket.max_len and \
-                alloc.can_admit(req.prompt, need, reserve=reserve,
-                                lazy=lazy)
+            if pos + (need - shared) > bucket.max_len:
+                return False
+            if alloc.can_admit(req.prompt, need, reserve=reserve,
+                               lazy=lazy):
+                return True
+            refused_pages = True
+            return False
 
         admitted: List[int] = []
         for b in range(bucket.batch):
@@ -347,20 +399,28 @@ class ContinuousScheduler:
             chosen = self.admission.select(pending, fits, now)
             if chosen is None:
                 break
-            if alloc is not None:
-                need = len(chosen.prompt) + chosen.max_new_tokens - 1
-                lease = alloc.admit(chosen.prompt, need, lazy=lazy)
-                if lease is None:
-                    # the page budget moved between fits and admit
-                    # (eviction edge): requeue at the head, stop filling
-                    pending.appendleft(chosen)
-                    break
-                slots[b] = _Slot(chosen, start=pos - lease.shared_len,
-                                 fed=lease.shared_len, pages=lease,
-                                 acc=[] if self.spec is not None else None)
-            else:
-                slots[b] = _Slot(chosen, start=pos,
-                                 acc=[] if self.spec is not None else None)
+            span_stats = {"rid": chosen.request_id, "lane": b}
+            if chosen.t_intake is not None:
+                span_stats["queue_us"] = round(
+                    (t_boundary - chosen.t_intake) * 1e6)
+            with _span("serve.admit_request", **span_stats):
+                if alloc is not None:
+                    need = len(chosen.prompt) + chosen.max_new_tokens - 1
+                    lease = alloc.admit(chosen.prompt, need, lazy=lazy)
+                    if lease is None:
+                        # the page budget moved between fits and admit
+                        # (eviction edge): requeue at the head, stop
+                        pending.appendleft(chosen)
+                        refused_pages = True
+                        break
+                    slots[b] = _Slot(
+                        chosen, start=pos - lease.shared_len,
+                        fed=lease.shared_len, pages=lease,
+                        acc=[] if self.spec is not None else None)
+                else:
+                    slots[b] = _Slot(
+                        chosen, start=pos,
+                        acc=[] if self.spec is not None else None)
             admitted.append(b)
             self.admissions += 1
             self.events.append(SlotEvent("admit", pos, b, chosen.request_id))
@@ -369,6 +429,11 @@ class ContinuousScheduler:
                 self.refills += 1
                 self.refill_gap_total += gap
                 self.max_refill_gap = max(self.max_refill_gap, gap)
+        if chance:
+            self.admit_chances += 1
+            if not admitted:
+                self.admit_stalls += 1
+                self.admit_stalls_pages += refused_pages
         return admitted
 
     # -- dispatch -------------------------------------------------------------
@@ -378,7 +443,8 @@ class ContinuousScheduler:
         """Drain the queue through successive continuous dispatches."""
         results: Dict[str, RequestResult] = {}
         while pending:
-            res = self._dispatch(pending, params, metrics)
+            with _span("serve.dispatch"):
+                res = self._dispatch(pending, params, metrics)
             # cancels that raced a completion from an EARLIER dispatch:
             # drop the old tokens BEFORE merging this dispatch's results,
             # so a request legitimately resubmitted under the same id
@@ -466,6 +532,8 @@ class ContinuousScheduler:
                   metrics: Dict[str, BucketMetrics]
                   ) -> Dict[str, RequestResult]:
         t0 = time.perf_counter()
+        boundary = _Boundary()
+        boundary.open()
         k = self.steps_per_dispatch
         # the policy's top pick sizes the dispatch bucket (FIFO: queue
         # head — unchanged; priority/EDF: the most urgent request)
@@ -534,209 +602,229 @@ class ContinuousScheduler:
         while pos + k <= L:
             # ---- micro-run boundary: hook, cancels, frees, admission ----
             if self.on_boundary is not None:
-                self.on_boundary(pos, slots)
-            cancel_mask = np.zeros((B,), bool)
-            for b, slot in enumerate(slots):
-                if slot is None:
-                    continue
-                if slot.req.request_id in self._canceled:
-                    self._canceled.discard(slot.req.request_id)
-                    self.cancellations += 1
-                    cancel_mask[b] = True
-                    self._free(slots, b, pos, freed_at)
-                elif slot.end_step < pos:
-                    self._free(slots, b, pos, freed_at, done)
-            if cancel_mask.any():
-                # wipe the canceled lanes NOW: even if no successor is
-                # admitted this dispatch, the state pytree must not carry
-                # a dead request's KV/SSM past the boundary
-                state = self.pool.reset_slots(B, L, state, cancel_mask)
-            drain_cancels()
-            if alloc is not None:
-                # incremental publish: every fully teacher-forced prompt
-                # page of a still-running request becomes a prefix-cache
-                # entry NOW, so a follower admitted at this boundary can
-                # already share it
-                for slot in slots:
-                    if slot is not None and slot.pages is not None:
-                        alloc.publish(slot.pages, slot.fed)
-
-            fresh = np.zeros((k, B), bool)
-            for b in self._admit(pending, bucket, slots, pos, freed_at):
-                fresh[0, b] = True
-                ever_used[b] = True
-            if self.spec is not None and alloc is not None:
-                # extend every live lane's lease with revocable draft
-                # pages covering this micro-run's write front BEFORE the
-                # page table is built; a lane the pool cannot cover parks
-                # (requeued as a continuation, lease released) so the
-                # remaining lanes keep making progress — the deadlock
-                # valve for eviction-pressure corner cases the admission
-                # reserve does not see
-                parked: List[DecodeRequest] = []
+                with _span("serve.intake"):
+                    self.on_boundary(pos, slots)
+            with _span("serve.free"):
+                cancel_mask = np.zeros((B,), bool)
                 for b, slot in enumerate(slots):
                     if slot is None:
                         continue
-                    live = min(k, slot.end_step - pos + 1)
-                    if alloc.draft_lease(slot.pages,
-                                         pos - slot.start + live):
-                        continue
-                    if sum(1 for s in slots if s is not None) == 1:
-                        raise RuntimeError(
-                            "page pool cannot extend the sole speculative "
-                            "lane's draft lease: page_count is too small "
-                            "for spec mode (validate_paged_spec should "
-                            "have rejected this geometry)")
-                    self._park(slots, b, pos, freed_at, done, parked)
-                    fresh[0, b] = False
-                for cont in reversed(parked):
-                    pending.appendleft(cont)
+                    if slot.req.request_id in self._canceled:
+                        self._canceled.discard(slot.req.request_id)
+                        self.cancellations += 1
+                        cancel_mask[b] = True
+                        self._free(slots, b, pos, freed_at)
+                    elif slot.end_step < pos:
+                        self._free(slots, b, pos, freed_at, done)
+                if cancel_mask.any():
+                    # wipe the canceled lanes NOW: even if no successor is
+                    # admitted this dispatch, the state pytree must not carry
+                    # a dead request's KV/SSM past the boundary
+                    state = self.pool.reset_slots(B, L, state, cancel_mask)
+                drain_cancels()
+                if alloc is not None:
+                    # incremental publish: every fully teacher-forced prompt
+                    # page of a still-running request becomes a prefix-cache
+                    # entry NOW, so a follower admitted at this boundary can
+                    # already share it
+                    for slot in slots:
+                        if slot is not None and slot.pages is not None:
+                            alloc.publish(slot.pages, slot.fed)
+
+            with _span("serve.admit"):
+                fresh = np.zeros((k, B), bool)
+                for b in self._admit(pending, bucket, slots, pos, freed_at):
+                    fresh[0, b] = True
+                    ever_used[b] = True
+                if self.spec is not None and alloc is not None:
+                    # extend every live lane's lease with revocable draft
+                    # pages covering this micro-run's write front BEFORE the
+                    # page table is built; a lane the pool cannot cover parks
+                    # (requeued as a continuation, lease released) so the
+                    # remaining lanes keep making progress — the deadlock
+                    # valve for eviction-pressure corner cases the admission
+                    # reserve does not see
+                    parked: List[DecodeRequest] = []
+                    for b, slot in enumerate(slots):
+                        if slot is None:
+                            continue
+                        live = min(k, slot.end_step - pos + 1)
+                        if alloc.draft_lease(slot.pages,
+                                             pos - slot.start + live):
+                            continue
+                        if sum(1 for s in slots if s is not None) == 1:
+                            raise RuntimeError(
+                                "page pool cannot extend the sole speculative "
+                                "lane's draft lease: page_count is too small "
+                                "for spec mode (validate_paged_spec should "
+                                "have rejected this geometry)")
+                        self._park(slots, b, pos, freed_at, done, parked)
+                        fresh[0, b] = False
+                    for cont in reversed(parked):
+                        pending.appendleft(cont)
             if all(s is None for s in slots):
                 break                  # drained, or out of positions
 
-            # ---- precompute the [k, B] schedule for this micro-run ----
-            feed = np.zeros((k, B), np.int32)
-            # empty lanes window to their own single position: harmless
-            start = np.broadcast_to(
-                np.arange(pos, pos + k, dtype=np.int32)[:, None],
-                (k, B)).copy()
-            active = np.zeros((k, B), bool)
-            lives = [0] * B           # spec acceptance re-walks live steps
-            feeds_n = [0] * B         # prompt feeds among them (never rolled
-            for b, slot in enumerate(slots):     # back: feeds come first)
-                if slot is None:
-                    idle_steps[b] += k
-                    self.idle_slot_steps += k
-                    continue
-                # steps this request still runs inside the micro-run;
-                # beyond them the slot self-masks (active False)
-                live = min(k, slot.end_step - pos + 1)
-                lives[b] = live
-                active[:live, b] = True
-                start[:, b] = slot.start
-                idle_steps[b] += k - live
-                self.idle_slot_steps += k - live
-                for i in range(live):
-                    if slot.fed < len(slot.req.prompt):
-                        feed[i, b] = slot.req.prompt[slot.fed]
-                        slot.fed += 1
-                        feeds_n[b] += 1
-                    else:
-                        feed[i, b] = -1   # continue from the slot's argmax
+            with _span("serve.schedule"):
+                # ---- precompute the [k, B] schedule for this micro-run ----
+                feed = np.zeros((k, B), np.int32)
+                # empty lanes window to their own single position: harmless
+                start = np.broadcast_to(
+                    np.arange(pos, pos + k, dtype=np.int32)[:, None],
+                    (k, B)).copy()
+                active = np.zeros((k, B), bool)
+                lives = [0] * B           # spec acceptance re-walks live steps
+                feeds_n = [0] * B         # prompt feeds among them (never rolled
+                for b, slot in enumerate(slots):     # back: feeds come first)
+                    if slot is None:
+                        idle_steps[b] += k
+                        self.idle_slot_steps += k
+                        continue
+                    # steps this request still runs inside the micro-run;
+                    # beyond them the slot self-masks (active False)
+                    live = min(k, slot.end_step - pos + 1)
+                    lives[b] = live
+                    active[:live, b] = True
+                    start[:, b] = slot.start
+                    idle_steps[b] += k - live
+                    self.idle_slot_steps += k - live
+                    for i in range(live):
+                        if slot.fed < len(slot.req.prompt):
+                            feed[i, b] = slot.req.prompt[slot.fed]
+                            slot.fed += 1
+                            feeds_n[b] += 1
+                        else:
+                            feed[i, b] = -1   # continue from the slot's argmax
 
-            extra = ()
-            if paged is not None:
-                # [B, n_tables] page table: the lease's pages first, the
-                # lane's pinned scratch page everywhere else (tail entries
-                # absorb clamped post-end writes; gathers of them are
-                # masked by kv_valid)
-                table = np.empty((B, n_tables), np.int32)
-                for b, slot in enumerate(slots):
-                    table[b, :] = scratch[b]
-                    if slot is not None and slot.pages is not None:
-                        # speculative mode appends the revocable draft
-                        # pages after the committed run, so the table
-                        # covers the lane's whole write front this
-                        # micro-run; ``draft`` is empty otherwise
-                        pg = slot.pages.pages + slot.pages.draft
-                        table[b, :len(pg)] = pg
-                extra = (lane("table", table, table_sh),)
+                table = None
+                if paged is not None:
+                    # [B, n_tables] page table: the lease's pages first, the
+                    # lane's pinned scratch page everywhere else (tail entries
+                    # absorb clamped post-end writes; gathers of them are
+                    # masked by kv_valid)
+                    table = np.empty((B, n_tables), np.int32)
+                    for b, slot in enumerate(slots):
+                        table[b, :] = scratch[b]
+                        if slot is not None and slot.pages is not None:
+                            # speculative mode appends the revocable draft
+                            # pages after the committed run, so the table
+                            # covers the lane's whole write front this
+                            # micro-run; ``draft`` is empty otherwise
+                            pg = slot.pages.pages + slot.pages.draft
+                            table[b, :len(pg)] = pg
+            with _span("serve.launch"):
+                extra = ()
+                if table is not None:
+                    extra = (lane("table", table, table_sh),)
+                if self.spec is None:
+                    toks, prev, state = exe.compiled(
+                        params, state,
+                        lane("feed", feed), prev,
+                        jax.device_put(np.int32(pos), pos_sh),
+                        lane("start", start),
+                        lane("active", active),
+                        lane("fresh", fresh),
+                        *extra)
+                else:
+                    verify, drafts, state = exe.compiled(
+                        params, state,
+                        lane("feed", feed),
+                        jax.device_put(prev_host.copy(), prev_sh),
+                        jax.device_put(np.int32(pos), pos_sh),
+                        lane("start", start),
+                        lane("active", active),
+                        lane("fresh", fresh),
+                        *extra)
+            # the device now has work: the boundary ends here, and the
+            # next one opens when this micro-run's tokens are on the host
+            self.boundary_seconds += boundary.close()
             if self.spec is not None:
                 # fused draft-scan + block-verify: the host accepts the
                 # longest draft prefix the target agrees with and rolls
                 # the rest back by bumping the slot's window start (free
                 # in the executable's local coordinates)
-                verify, drafts, state = exe.compiled(
-                    params, state,
-                    lane("feed", feed),
-                    jax.device_put(prev_host.copy(), prev_sh),
-                    jax.device_put(np.int32(pos), pos_sh),
-                    lane("start", start),
-                    lane("active", active),
-                    lane("fresh", fresh),
-                    *extra)
-                vt = np.asarray(jax.device_get(verify))
-                dt = np.asarray(jax.device_get(drafts))
-                deltas: Dict[str, List[int]] = {}
-                for b, slot in enumerate(slots):
-                    if slot is None:
-                        continue
-                    live = lives[b]
-                    # step i consumed the right token iff it was a prompt
-                    # feed, the host-correct prev (i == 0), or the draft
-                    # matched the target at step i-1; validity is closed
-                    # under prefixes, so the accepted set is {0..n-1}
-                    n = 0
-                    for i in range(live):
-                        if feed[i, b] >= 0 or i == 0 or \
-                                dt[i - 1, b] == vt[i - 1, b]:
-                            n += 1
-                        else:
-                            break
-                    n_dec = live - feeds_n[b]
-                    if n_dec > 0:
-                        self.spec_verifies += 1
-                        self.spec_draft_tokens += n_dec
-                        self.spec_accepted_tokens += n - feeds_n[b]
-                    first = slot.start + len(slot.req.prompt) - 1
-                    new = [int(vt[i, b]) for i in range(n)
-                           if pos + i >= first]
-                    slot.acc.extend(new)
-                    slot.prev_tok = int(vt[n - 1, b])
-                    prev_host[b] = slot.prev_tok
-                    if n < live:
-                        self.spec_rollbacks += 1
-                    if alloc is not None and slot.pages is not None:
-                        # resolve the lane's draft pages against the
-                        # committed cursor IN THE CURRENT local frame —
-                        # before the start bump below moves the origin:
-                        # pages fully below ``local0 + n`` splice into
-                        # the committed run, the rest roll back
-                        alloc.resolve_draft(slot.pages,
-                                            pos - slot.start + n)
-                    # the universal bump k - n advances the slot's local
-                    # cursor by exactly n: rejected steps replay next
-                    # micro-run, and a fully-accepted short lane (live <
-                    # k) still lands end_step at pos + live - 1 + (k -
-                    # live) = pos + k - 1, so it frees at the boundary
-                    slot.start += k - n
-                    if new:
-                        deltas[slot.req.request_id] = new
-                if deltas and self.on_tokens is not None:
-                    self.on_tokens(deltas)
+                with _span("serve.fetch"):
+                    vt = np.asarray(jax.device_get(verify))
+                    dt = np.asarray(jax.device_get(drafts))
+                boundary.open()
+                with _span("serve.emit"):
+                    deltas: Dict[str, List[int]] = {}
+                    for b, slot in enumerate(slots):
+                        if slot is None:
+                            continue
+                        live = lives[b]
+                        # step i consumed the right token iff it was a prompt
+                        # feed, the host-correct prev (i == 0), or the draft
+                        # matched the target at step i-1; validity is closed
+                        # under prefixes, so the accepted set is {0..n-1}
+                        n = 0
+                        for i in range(live):
+                            if feed[i, b] >= 0 or i == 0 or \
+                                    dt[i - 1, b] == vt[i - 1, b]:
+                                n += 1
+                            else:
+                                break
+                        n_dec = live - feeds_n[b]
+                        if n_dec > 0:
+                            self.spec_verifies += 1
+                            self.spec_draft_tokens += n_dec
+                            self.spec_accepted_tokens += n - feeds_n[b]
+                        first = slot.start + len(slot.req.prompt) - 1
+                        new = [int(vt[i, b]) for i in range(n)
+                               if pos + i >= first]
+                        slot.acc.extend(new)
+                        slot.prev_tok = int(vt[n - 1, b])
+                        prev_host[b] = slot.prev_tok
+                        if n < live:
+                            self.spec_rollbacks += 1
+                        if alloc is not None and slot.pages is not None:
+                            # resolve the lane's draft pages against the
+                            # committed cursor IN THE CURRENT local frame —
+                            # before the start bump below moves the origin:
+                            # pages fully below ``local0 + n`` splice into
+                            # the committed run, the rest roll back
+                            alloc.resolve_draft(slot.pages,
+                                                pos - slot.start + n)
+                        # the universal bump k - n advances the slot's local
+                        # cursor by exactly n: rejected steps replay next
+                        # micro-run, and a fully-accepted short lane (live <
+                        # k) still lands end_step at pos + live - 1 + (k -
+                        # live) = pos + k - 1, so it frees at the boundary
+                        slot.start += k - n
+                        if new:
+                            deltas[slot.req.request_id] = new
+                    if deltas and self.on_tokens is not None:
+                        self.on_tokens(deltas)
                 self.micro_runs += 1
                 self.steps += k
                 self.slot_steps += k * B
                 pos += k
                 runs += 1
                 continue
-            toks, prev, state = exe.compiled(
-                params, state,
-                lane("feed", feed), prev,
-                jax.device_put(np.int32(pos), pos_sh),
-                lane("start", start),
-                lane("active", active),
-                lane("fresh", fresh),
-                *extra)
             if self.on_tokens is not None:
                 # streaming: fetch this micro-run's block at the boundary
                 # and hand each live request its newly GENERATED tokens
                 # (prompt-echo steps are not part of any stream). The
                 # fetched array replaces the device block in `outs`, so
                 # drain-time assembly pays no second transfer.
-                toks = np.asarray(jax.device_get(toks))
-                deltas: Dict[str, List[int]] = {}
-                for b, slot in enumerate(slots):
-                    if slot is None:
-                        continue
-                    first = slot.start + len(slot.req.prompt) - 1
-                    lo = max(pos, first)
-                    hi = min(pos + k - 1, slot.end_step)
-                    if lo <= hi:
-                        deltas[slot.req.request_id] = [
-                            int(t) for t in toks[lo - pos:hi - pos + 1, b]]
-                if deltas:
-                    self.on_tokens(deltas)
+                with _span("serve.fetch"):
+                    toks = np.asarray(jax.device_get(toks))
+                boundary.open()
+                with _span("serve.emit"):
+                    deltas: Dict[str, List[int]] = {}
+                    for b, slot in enumerate(slots):
+                        if slot is None:
+                            continue
+                        first = slot.start + len(slot.req.prompt) - 1
+                        lo = max(pos, first)
+                        hi = min(pos + k - 1, slot.end_step)
+                        if lo <= hi:
+                            deltas[slot.req.request_id] = [int(t) for t in
+                                toks[lo - pos:hi - pos + 1, b]]
+                    if deltas:
+                        self.on_tokens(deltas)
+            else:
+                boundary.open()
             outs.append(toks)
             self.micro_runs += 1
             self.steps += k
@@ -744,71 +832,74 @@ class ContinuousScheduler:
             pos += k
             runs += 1
 
-        # every admitted request ends inside the loop (admission bounds
-        # end_step < L and micro-runs tile [0, L)), so drain the rest —
-        # except in spec mode, where rollback bumps can push a request's
-        # end_step past the bucket's positions: those requeue as
-        # continuations whose prompt carries everything committed so far
-        requeues: List[DecodeRequest] = []
-        for b, slot in enumerate(slots):
-            if slot is None:
-                continue
-            if self.spec is not None and slot.end_step >= pos:
-                self._park(slots, b, pos, freed_at, done, requeues)
-            else:
-                self._free(slots, b, pos, freed_at, done)
-        for cont in reversed(requeues):
-            pending.appendleft(cont)
-        drain_cancels()   # marks set during the final micro-run
+        self.boundary_seconds += boundary.close()
 
-        if outs:
-            jax.block_until_ready(outs[-1])
-        self.pool.release(B, L, state)
-        t_total = time.perf_counter() - t0
-        self.dispatches += 1
-        self.dispatch_idle.append(idle_steps)
+        with _span("serve.free"):
+            # every admitted request ends inside the loop (admission bounds
+            # end_step < L and micro-runs tile [0, L)), so drain the rest —
+            # except in spec mode, where rollback bumps can push a request's
+            # end_step past the bucket's positions: those requeue as
+            # continuations whose prompt carries everything committed so far
+            requeues: List[DecodeRequest] = []
+            for b, slot in enumerate(slots):
+                if slot is None:
+                    continue
+                if self.spec is not None and slot.end_step >= pos:
+                    self._park(slots, b, pos, freed_at, done, requeues)
+                else:
+                    self._free(slots, b, pos, freed_at, done)
+            for cont in reversed(requeues):
+                pending.appendleft(cont)
+            drain_cancels()   # marks set during the final micro-run
 
-        toks = (np.concatenate(
-            [np.asarray(jax.device_get(t)) for t in outs], axis=0)
-            if outs else np.zeros((0, B), np.int32))   # [steps, B]
-        results: Dict[str, RequestResult] = {}
-        for req, b, s, acc in done:
-            if acc is not None:
-                # spec mode: host-committed tokens, prefixed by whatever
-                # earlier continuation legs carried over
-                tokens = self._spec_carry.pop(req.request_id, []) + acc
-            else:
-                first = s + len(req.prompt) - 1
-                tokens = [int(t) for t in
-                          toks[first:first + req.max_new_tokens, b]]
-            results[req.request_id] = RequestResult(
-                request_id=req.request_id,
-                tokens=tokens,
-                bucket=bucket.label,
-                prefill_seconds=0.0,   # prefill is folded into the steps
-                total_seconds=t_total,
-            )
+        with _span("serve.drain"):
+            if outs:
+                jax.block_until_ready(outs[-1])
+            self.pool.release(B, L, state)
+            t_total = time.perf_counter() - t0
+            self.dispatches += 1
 
-        m = metrics.setdefault(bucket.label, BucketMetrics())
-        m.dispatches += 1
-        m.requests += len(results)
-        # same unit as the fifo path: slots this dispatch never filled
-        # (mid-dispatch idling lives in slot_steps/busy_slot_steps)
-        m.padded_slots += B - sum(ever_used)
-        m.new_tokens += sum(len(r.tokens) for r in results.values())
-        m.decode_seconds += t_total
-        m.latencies.extend([t_total] * len(results))
-        span = runs * k
-        m.slot_steps += span * B
-        for b in range(B):
-            m.busy_slot_steps += span - idle_steps[b]
-            m.slot_idle.append(idle_steps[b])
-        if alloc is not None:
-            # gauges, not sums: the page pool is shared process-wide
-            m.pages_in_use = alloc.pages_in_use
-            m.peak_pages = alloc.peak_pages
-            m.prefix_hits = alloc.prefix_hits
-        return results
+            toks = (np.concatenate(
+                [np.asarray(jax.device_get(t)) for t in outs], axis=0)
+                if outs else np.zeros((0, B), np.int32))   # [steps, B]
+            results: Dict[str, RequestResult] = {}
+            for req, b, s, acc in done:
+                if acc is not None:
+                    # spec mode: host-committed tokens, prefixed by whatever
+                    # earlier continuation legs carried over
+                    tokens = self._spec_carry.pop(req.request_id, []) + acc
+                else:
+                    first = s + len(req.prompt) - 1
+                    tokens = [int(t) for t in
+                              toks[first:first + req.max_new_tokens, b]]
+                results[req.request_id] = RequestResult(
+                    request_id=req.request_id,
+                    tokens=tokens,
+                    bucket=bucket.label,
+                    prefill_seconds=0.0,   # prefill is folded into the steps
+                    total_seconds=t_total,
+                )
+
+            m = metrics.setdefault(bucket.label, BucketMetrics())
+            m.dispatches += 1
+            m.requests += len(results)
+            # same unit as the fifo path: slots this dispatch never filled
+            # (mid-dispatch idling lives in slot_steps/busy_slot_steps)
+            m.padded_slots += B - sum(ever_used)
+            m.new_tokens += sum(len(r.tokens) for r in results.values())
+            m.decode_seconds += t_total
+            m.latencies.extend([t_total] * len(results))
+            span = runs * k
+            m.slot_steps += span * B
+            for b in range(B):
+                m.busy_slot_steps += span - idle_steps[b]
+                m.slot_idle.append(idle_steps[b])
+            if alloc is not None:
+                # gauges, not sums: the page pool is shared process-wide
+                m.pages_in_use = alloc.pages_in_use
+                m.peak_pages = alloc.peak_pages
+                m.prefix_hits = alloc.prefix_hits
+            return results
 
     # -- observability --------------------------------------------------------
 
@@ -832,6 +923,10 @@ class ContinuousScheduler:
                 self.refill_gap_total / self.refills, 3)
             if self.refills else 0.0,
             "max_refill_gap": self.max_refill_gap,
+            "boundary_seconds": self.boundary_seconds,
+            "admit_chances": self.admit_chances,
+            "admit_stalls": self.admit_stalls,
+            "admit_stalls_pages": self.admit_stalls_pages,
         }
         if self.spec is not None:
             out["spec"] = {

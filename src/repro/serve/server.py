@@ -41,6 +41,12 @@ touches its own per-request queues. Every hot-path executable is the
 same warm ``masked_decode`` the blocking path uses — streaming adds one
 host fetch per micro-run and ZERO lowerings (pinned in
 ``tests/test_server.py`` along with token parity against ``run()``).
+
+Each request is stamped (``DecodeRequest.t_intake``) when the server
+takes it in, so the scheduler's ``serve.admit_request`` span can say how
+long it queued; the worker's wait for work with nothing pending is the
+span ``serve.idle``. The scheduler's own spans and counters name the
+rest of the worker's time (``repro.serve.scheduler``).
 """
 
 from __future__ import annotations
@@ -52,6 +58,8 @@ import queue
 import threading
 import time
 from typing import AsyncIterator, Deque, Dict, List, Optional
+
+import jax
 
 from repro.serve.batcher import (
     DecodeRequest,
@@ -166,6 +174,7 @@ class AsyncServeServer:
         if rid in self._streams:
             raise ValueError(f"duplicate request id {rid!r}: a stream "
                              "with this id is already open")
+        request = dataclasses.replace(request, t_intake=time.perf_counter())
         s = _Stream(request, asyncio.Queue(), t_submit=time.monotonic())
         self._streams[rid] = s
         self._intake.put(("submit", request))
@@ -237,10 +246,12 @@ class AsyncServeServer:
     def _worker(self) -> None:
         batcher = self.batcher
         while True:
-            try:
-                item = self._intake.get(timeout=self.poll_s)
-            except queue.Empty:
-                item = None
+            # run() returned with the queue drained: wait for work
+            with jax.profiler.TraceAnnotation("serve.idle"):
+                try:
+                    item = self._intake.get(timeout=self.poll_s)
+                except queue.Empty:
+                    item = None
             if item is not None:
                 self._apply(item)
             self._drain_intake()

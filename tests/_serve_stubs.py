@@ -17,8 +17,11 @@ mis-slice, or double-completion corrupts the receipt.
 from __future__ import annotations
 
 import collections
+import glob
+import os
 import types
 
+import jax
 import numpy as np
 
 from repro.serve import Bucket, BucketPolicy, DecodeRequest
@@ -43,18 +46,32 @@ class HostExe:
         return toks, toks[-1], state
 
 
+class PagedHostExe(HostExe):
+    """HostExe with the paged 9th input (the page table)."""
+
+    def __init__(self):
+        super().__init__()
+        self.bundle = types.SimpleNamespace(in_shardings=(None,) * 9)
+
+    def compiled(self, params, state, feed, prev, pos, start, active,
+                 fresh, table):
+        return super().compiled(params, state, feed, prev, pos, start,
+                                active, fresh)
+
+
 class HostPlan:
-    """Plan stand-in: one HostExe per (batch, max_len, k)."""
+    """Plan stand-in: one HostExe (PagedHostExe under ``paged=``) per
+    (batch, max_len, k, paged)."""
 
     def __init__(self):
         self.exes = {}
 
     def serve_executable(self, kind, *, batch, max_len,
-                         steps_per_dispatch=1, **kw):
+                         steps_per_dispatch=1, paged=None, **kw):
         assert kind == "masked_decode"
-        key = (batch, max_len, steps_per_dispatch)
+        key = (batch, max_len, steps_per_dispatch, paged)
         if key not in self.exes:
-            self.exes[key] = HostExe()
+            self.exes[key] = HostExe() if paged is None else PagedHostExe()
         return self.exes[key]
 
 
@@ -412,3 +429,29 @@ def run_host_trace(lengths, k, batch, max_len=64, cancel_at=None,
     pending = collections.deque(reqs)
     results = sched.run(pending, None, {})
     return sched, reqs, results, canceled
+
+
+def traced(log_dir, fn):
+    """``fn()`` under the profiler; returns its result and the trace's
+    ``serve.*`` events as ``(name, start_ns, end_ns, stats)``."""
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    spans = []
+    for plane in pd.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("serve."):
+                    s = float(ev.start_ns)
+                    spans.append((ev.name, s, s + float(ev.duration_ns),
+                                  dict(ev.stats)))
+    return out, spans
+
+
+def named(spans, name):
+    return [sp for sp in spans if sp[0] == name]

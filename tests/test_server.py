@@ -27,6 +27,7 @@ import threading
 import time
 
 import pytest
+from _serve_stubs import named, traced
 
 from repro.configs import reduced_config
 from repro.plan import MeshSpec, build_plan
@@ -316,6 +317,43 @@ def test_server_requires_continuous_schedule_and_start(plan, test_seed):
             await server.generate(DecodeRequest("r", [1], max_new_tokens=1))
 
     asyncio.run(unstarted())
+
+
+def test_intake_stamp_spans_and_tokens_with_profiler_on_and_off(
+        plan, test_seed, tmp_path):
+    """The server stamps each request at intake (``ServeBatcher.submit``
+    stamps one nobody did, on a copy), every admission is a
+    ``serve.admit_request`` span with the request's id and its queue
+    wait, the idle worker is in ``serve.idle``, and the tokens do not
+    depend on whether the profiler is on."""
+    b = make_batcher(plan, test_seed)
+    req = DecodeRequest("stamp", [1, 2], max_new_tokens=2)
+    t0 = time.perf_counter()
+    b.submit(req)
+    assert req.t_intake is None
+    assert t0 <= b._pending[-1].t_intake <= time.perf_counter()
+
+    def serve():
+        ba = make_batcher(plan, test_seed)
+
+        async def drive():
+            async with AsyncServeServer(ba) as server:
+                await asyncio.sleep(0.02)           # idle worker
+                return await asyncio.gather(*[
+                    server.generate(DecodeRequest(rid, p, max_new_tokens=n))
+                    for rid, p, n in _TRACE])
+
+        with plan.activate():
+            return {r.request_id: r.tokens for r in asyncio.run(drive())}
+
+    off = serve()
+    on, spans = traced(tmp_path, serve)
+    assert on == off
+    admits = named(spans, "serve.admit_request")
+    assert sorted(sp[3]["rid"] for sp in admits) == \
+        sorted(rid for rid, _, _ in _TRACE)
+    assert all(sp[3]["queue_us"] >= 0 for sp in admits)
+    assert named(spans, "serve.idle")
 
 
 def test_quantile_nearest_rank_small_samples():
