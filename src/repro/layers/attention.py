@@ -261,7 +261,7 @@ def decode_self_attention(
 def paged_decode_self_attention(
     params: dict,
     x: jnp.ndarray,              # [B, 1, d] current token hidden
-    cache_k: jnp.ndarray,        # [P, ps, KV, hd] this layer's page pool
+    cache_k: jnp.ndarray,        # [P, ps, KV, hd], or [L, P, ps, KV, hd]
     cache_v: jnp.ndarray,
     pages,                       # models.base.PageView (table, local_pos, ps)
     *,
@@ -269,6 +269,7 @@ def paged_decode_self_attention(
     n_kv: int,
     head_dim: int,
     rope_theta: float = 10000.0,
+    layer: Optional[jnp.ndarray] = None,   # [] int32: index into [L, ...]
 ):
     """One decode step against the paged KV layout.
 
@@ -288,6 +289,15 @@ def paged_decode_self_attention(
     nothing reads. Reads gather the slot's whole table back into
     ``[B, S, KV, hd]`` and mask to ``local_index <= local_pos[b]``.
 
+    With ``layer`` set, ``cache_k``/``cache_v`` are the whole stacked
+    pool ``[L, P, ps, KV, hd]`` that the caller's layer scan carries: the
+    row is scattered to ``(layer, page, offset)``, one head at a time,
+    and the table gathered from ``(layer, table)``, so no layer slab is
+    sliced out or written back and the donated pool is updated in place
+    (``DecoderLM``'s paged decode). ``layer=None`` takes one layer's
+    ``[P, ps, KV, hd]`` slab, as the per-layer callers (encoder-decoder,
+    hybrid) pass it.
+
     Returns (out [B,1,d], new_pool_k, new_pool_v).
     """
     B = x.shape[0]
@@ -304,10 +314,21 @@ def paged_decode_self_attention(
     page_ids = jnp.take_along_axis(
         pages.table, (local // ps)[:, None], axis=1)[:, 0]
     offs = local % ps
-    cache_k = cache_k.at[page_ids, offs].set(k[:, 0])
-    cache_v = cache_v.at[page_ids, offs].set(v[:, 0])
-    k_all = cache_k[pages.table].reshape(B, S, n_kv, head_dim)
-    v_all = cache_v[pages.table].reshape(B, S, n_kv, head_dim)
+    if layer is None:
+        row, read = (page_ids, offs), (pages.table,)
+    else:
+        # one [hd] row per slot and head: contiguous in whichever order
+        # the device lays a page's rows out in (the TPU compiler keeps
+        # qwen1.5-4b's 20-head pool heads-major, yi-6b's 4-head pool
+        # row-major), so the carried pool keeps its entry layout and is
+        # never relaid out on the way in and out
+        row = (layer, page_ids[:, None], offs[:, None],
+               jnp.arange(n_kv)[None, :])
+        read = (layer, pages.table)
+    cache_k = cache_k.at[row].set(k[:, 0])
+    cache_v = cache_v.at[row].set(v[:, 0])
+    k_all = cache_k[read].reshape(B, S, n_kv, head_dim)
+    v_all = cache_v[read].reshape(B, S, n_kv, head_dim)
     kv_valid = jnp.arange(S)[None, :] <= local[:, None]
     o = mha(q, k_all, v_all, causal=False, kv_valid=kv_valid)
     out = linear(params["wo"], o.reshape(B, 1, n_heads * head_dim))

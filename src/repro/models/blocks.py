@@ -89,13 +89,14 @@ def attn_block_decode(
     params: dict,
     x: jnp.ndarray,              # [B, 1, d] (or [B, m, d] with ``local``)
     cache_k: jnp.ndarray,        # dense [B,S,KV,hd] or paged [P,ps,KV,hd]
-    cache_v: jnp.ndarray,
+    cache_v: jnp.ndarray,        # (paged with ``layer``: [L,P,ps,KV,hd])
     pos: jnp.ndarray,
     cfg: ArchConfig,
     *,
     window_start: Optional[jnp.ndarray] = None,   # [B] int32 slot windows
     pages=None,                  # models.base.PageView: paged KV layout
     local: Optional[jnp.ndarray] = None,   # [B] int32: local block coords
+    layer: Optional[jnp.ndarray] = None,   # [] int32: paged, stacked pool
 ):
     h = rmsnorm(params["ln1"], x)
     if local is not None and pages is not None:
@@ -120,7 +121,7 @@ def attn_block_decode(
         h, ck, cv = paged_decode_self_attention(
             params["attn"], h, cache_k, cache_v, pages,
             n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.head_dim,
-            rope_theta=cfg.rope_theta,
+            rope_theta=cfg.rope_theta, layer=layer,
         )
     else:
         h, ck, cv = decode_self_attention(

@@ -99,21 +99,38 @@ class DecoderLM:
         ``make_masked_decode_step``). With ``pages`` (a
         ``models.base.PageView``) the KV leaves are the shared page pool
         instead of per-slot slabs and ``window_start`` is unused: each
-        slot indexes (and RoPE-rotates at) its own local position.
+        slot indexes (and RoPE-rotates at) its own local position. The
+        pool then rides the layer scan's carry, not its ``xs``/``ys``:
+        each layer writes its rows at ``(layer, page, offset)`` and
+        gathers its pages from ``(layer, table)`` in the whole stacked
+        pool, so the donated buffer is updated in place and no layer
+        slab is sliced out or stacked back.
         """
         cfg = self.cfg
         x = embed(params["embed"], tokens[:, None])
 
-        def body(x, inp):
-            layer_params, ck, cv = inp
-            x, ck, cv = attn_block_decode(layer_params, x, ck, cv, pos, cfg,
-                                          window_start=window_start,
-                                          pages=pages)
-            return x, (ck, cv)
+        if pages is not None:
+            def paged_body(carry, inp):
+                x, ck, cv = carry
+                layer_params, layer = inp
+                x, ck, cv = attn_block_decode(layer_params, x, ck, cv, pos,
+                                              cfg, pages=pages, layer=layer)
+                return (x, ck, cv), None
 
-        x, (ck, cv) = jax.lax.scan(
-            body, x, (params["blocks"], state["cache_k"], state["cache_v"])
-        )
+            layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+            (x, ck, cv), _ = jax.lax.scan(
+                paged_body, (x, state["cache_k"], state["cache_v"]),
+                (params["blocks"], layers))
+        else:
+            def body(x, inp):
+                layer_params, ck, cv = inp
+                x, ck, cv = attn_block_decode(layer_params, x, ck, cv, pos,
+                                              cfg, window_start=window_start)
+                return x, (ck, cv)
+
+            x, (ck, cv) = jax.lax.scan(
+                body, x,
+                (params["blocks"], state["cache_k"], state["cache_v"]))
         x = rmsnorm(params["ln_f"], x)
         logits = decode_head_logits(params["head"]["w"], x, cfg)
         return logits, {"cache_k": ck, "cache_v": cv}

@@ -12,7 +12,9 @@ one process may hold the TPU library, and every test worker imports this
 file.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -54,11 +56,25 @@ def _peak(compiled) -> int:
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
-def _masked_step(plan, max_len: int = 2048):
+def _masked_step(plan, max_len: int = 2048, paged=auto_paged(SERVE)):
     """The micro-run the serve CLI dispatches: k=4, paged, batch 8."""
     return plan.serve_executable(
         "masked_decode", batch=8, max_len=max_len, steps_per_dispatch=4,
-        paged=auto_paged(SERVE)).compiled
+        paged=paged).compiled
+
+
+_BYTES = {"bf16": 2, "f32": 4, "s32": 4}
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* "
+                    r"([\w-]+)\(", re.M)
+_COLLECTIVES = ("all-gather", "all-reduce", "all-to-all",
+                "collective-permute", "reduce-scatter")
+
+
+def _instructions(text: str):
+    """(name, opcode, dtype, shape) of every array-valued instruction in
+    compiled HLO text."""
+    return [(n, op, d, tuple(int(x) for x in s.split(",") if x))
+            for n, d, s, op in _INSTR.findall(text)]
 
 
 @pytest.mark.parametrize("name,M,K,N,x_dtype,shift", [
@@ -114,3 +130,51 @@ def test_megatron_1x4_float_step_compiles(topo):
     # weights split four ways on the model axis; norms, the GQA kv
     # projections and the paged pool's replicated page axes stay whole
     assert per_device < 0.3 * whole, (per_device, whole)
+
+
+@pytest.mark.parametrize("arch,max_len,paged,model", [
+    ("yi-6b", 256, auto_paged(SERVE), 1),
+    ("yi-6b", 2048, auto_paged(SERVE), 1),
+    # 20 KV heads: the TPU compiler keeps this pool heads-major, which a
+    # [KV, hd] row write would make the step relay out on entry and exit
+    ("qwen1.5-4b", 2048, (336, 16), 1),
+    # megatron 1x4: the pool's head axis is split over the model axis,
+    # and the per-head row write indexes it
+    ("yi-6b", 2048, auto_paged(SERVE), 4),
+    ("qwen1.5-4b", 2048, (336, 16), 4),
+])
+def test_paged_micro_run_updates_the_pool_in_place(topo, arch, max_len,
+                                                   paged, model):
+    cfg = get_config(arch)
+    if model > 1:
+        cfg = cfg.with_(sharding_mode="megatron")
+    plan = build_plan(cfg, None,
+                      mesh_spec=MeshSpec.from_mesh(_mesh(topo, model)))
+    compiled = _masked_step(plan, max_len, paged)
+    text = compiled.as_text()
+    whole = (cfg.n_layers, paged[0], paged[1], cfg.n_kv, cfg.head_dim)
+    pool = whole[:3] + (cfg.n_kv // model, cfg.head_dim)    # one device's
+    slabs = (pool[1:], (1,) + pool[1:])
+    relaid = 0
+    for name, op, dtype, shape in _instructions(text):
+        # the layer scan carries the donated pool: no whole-pool copy, no
+        # stacked write-back and no layer slab sliced out of it
+        if shape == pool:
+            assert op != "copy" and "dynamic-update-slice" not in \
+                f"{op} {name}", name
+        assert not (shape in slabs and op in
+                    ("dynamic-slice", "fusion", "copy")), name
+        if model > 1:
+            # a sharded pool stays a quarter on each device, and no page
+            # crosses the mesh
+            assert shape != whole, name
+            assert not (op.startswith(_COLLECTIVES)
+                        and paged[0] in shape), name
+        if op == "copy" and len(shape) == 3 and shape[0] == cfg.n_layers:
+            relaid += _BYTES[dtype] * math.prod(shape)
+    # what the step keeps besides: re-laid-out copies of stacked weights
+    # (or of this device's slice of them), which the layer scan slices
+    # per layer
+    pool_leaf = 2 * math.prod(pool)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp - relaid < pool_leaf, (temp, relaid, pool_leaf)

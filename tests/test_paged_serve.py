@@ -21,6 +21,8 @@ The acceptance properties this file pins down (docs/memory_model.md):
 """
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import reduced_config
@@ -28,6 +30,7 @@ from repro.dist.sharding import init_params
 from repro.launch.mesh import make_debug_mesh
 from repro.models import build_model
 from repro.models.base import PAGED_STATE_KEYS, paged_state_specs
+from repro.plan import MeshSpec, build_plan
 from repro.serve import Bucket, BucketPolicy, DecodeRequest, ServeBatcher
 
 PAGED = (64, 16)          # (page_count, page_size) used throughout
@@ -162,6 +165,50 @@ def test_paged_matches_dense_on_hybrid_ssm(mesh, k, fifo_reference,
     for rid, _, _ in _PARITY_TRACE:
         assert ref[rid] == res[rid], (k, rid)
     _assert_reclaimed(b)
+
+
+def test_micro_run_writes_pool_rows_at_layer_page_offset(cfg, params):
+    """One paged micro-run writes each layer's K/V rows into the stacked
+    pool at ``(layer, table[b, local // ps], local % ps)`` — the rows the
+    dense-slab micro-run writes at the same positions — and leaves every
+    other row of the pool, and every page no table holds, as it was.
+
+    Both paths start from the same history: the dense slabs are the pool
+    read through the tables. Positions 14..17 cross a page boundary."""
+    k, B, S, ps, pos = 4, 2, 64, PAGED[1], 14
+    plan = build_plan(cfg, None, mesh_spec=MeshSpec.debug(1, 1))
+    table = np.array([[5, 9, 2, 30], [17, 3, 40, 11]], np.int32)
+    L, KV, hd = cfg.n_layers, cfg.n_kv, cfg.head_dim
+    keys = jax.random.split(jax.random.PRNGKey(7), 2)
+    pool = {n: jax.random.normal(key, (L, PAGED[0], ps, KV, hd),
+                                 jnp.bfloat16)
+            for n, key in zip(PAGED_STATE_KEYS, keys)}
+    dense = {n: v[:, table].reshape(L, B, S, KV, hd)
+             for n, v in pool.items()}
+    before = {n: np.asarray(v) for n, v in pool.items()}   # state donated
+    feed = np.array([[11, 40], [3, 7], [59, 21], [8, 33]], np.int32)
+    lanes = (feed, np.zeros(B, np.int32), np.int32(pos),
+             np.zeros((k, B), np.int32), np.ones((k, B), bool),
+             np.zeros((k, B), bool))
+    with plan.activate():
+        run = plan.serve_executable("masked_decode", batch=B, max_len=S,
+                                    steps_per_dispatch=k, paged=PAGED)
+        toks_p, _, out_p = run.compiled(params, pool, *lanes, table)
+        run = plan.serve_executable("masked_decode", batch=B, max_len=S,
+                                    steps_per_dispatch=k)
+        toks_d, _, out_d = run.compiled(params, dense, *lanes)
+    assert np.array_equal(toks_p, toks_d)
+    in_no_table = np.setdiff1d(np.arange(PAGED[0]), table)
+    rows = np.arange(pos, pos + k)
+    for n in PAGED_STATE_KEYS:
+        after = np.asarray(out_p[n])
+        assert np.array_equal(after[:, in_no_table],
+                              before[n][:, in_no_table]), n
+        want = before[n].copy()
+        for b in range(B):
+            want[:, table[b, rows // ps], rows % ps] = \
+                np.asarray(out_d[n])[:, b, rows]
+        assert np.array_equal(after, want), n
 
 
 # ---------------------------------------------------------------------------
